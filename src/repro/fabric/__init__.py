@@ -33,7 +33,7 @@ accesses because more weights stay resident. This package models that chip:
     (digital partial sums combined with a reduce-scatter over inter-chip
     links), batch over ``data``; divisibility fallbacks follow
     ``launch.shardings``. Execution backends: a host-sequential chip loop
-    or a real multi-device ``jax.experimental.shard_map`` SPMD program
+    or a real multi-device ``jax.shard_map`` SPMD program
     (``backend="auto"|"sequential"|"shard_map"``, ``resolve_backend``).
     ``sharded_fabric_report`` separates on-chip EMA from cross-chip link
     traffic and reports double-buffered round-overlap latency

@@ -41,7 +41,6 @@ from typing import List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -393,12 +392,12 @@ class FabricProgram:
         if has_key:
             in_specs.append(P())
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 chip_fn,
-                mesh,
+                mesh=mesh,
                 in_specs=tuple(in_specs),
                 out_specs=(P("data", None), P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
         )
         self._fns[cache_key] = fn

@@ -42,12 +42,12 @@ plan has no replication fallbacks, else falls back to the sequential loop.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -335,20 +335,28 @@ def _shard_map_matmul(x_int, w_int, sx, sw, sharded: ShardedPlacement, cim: CiMC
     stays bit-for-bit equal to the unsharded path.
     """
     fabric = sharded.chip_mesh.fabric
-    k_splits, d_splits = sharded.k_splits, sharded.d_splits
-    n = w_int.shape[1]
-    cols = fabric.cols
     k_tiles = math.ceil(sharded.k / fabric.rows)
-    mesh = make_chip_mesh(d_splits, k_splits, require_concrete=True)
-
     # pad K to whole tiles so every model-axis device gets an equal block;
     # _bitplane_matmul pads the ragged tail identically in the sequential path
     k_pad = k_tiles * fabric.rows - x_int.shape[1]
     if k_pad:
         x_int = jnp.pad(x_int, ((0, 0), (0, k_pad)))
         w_int = jnp.pad(w_int, ((0, k_pad), (0, 0)))
+    fn = _shard_map_program(
+        sharded.d_splits, sharded.k_splits, fabric.cols, cim, key is not None
+    )
+    args = (x_int, w_int, sx, sw) + ((key,) if key is not None else ())
+    return fn(*args)
 
-    has_key = key is not None
+
+@functools.lru_cache(maxsize=64)
+def _shard_map_program(
+    d_splits: int, k_splits: int, cols: int, cim: CiMConfig, has_key: bool
+):
+    """The jitted SPMD program of ``_shard_map_matmul`` for one mesh and CiM
+    configuration, built once: an eager ``shard_map`` of a fresh closure
+    would trace and dispatch every primitive on every call."""
+    mesh = make_chip_mesh(d_splits, k_splits, require_concrete=True)
 
     def chip_fn(x_blk, w_blk, sx_, sw_, *maybe_key):
         di = jax.lax.axis_index("data")
@@ -364,7 +372,7 @@ def _shard_map_matmul(x_int, w_int, sx, sw, sharded: ShardedPlacement, cim: CiMC
         )
         conversions, comparisons = st.conversions, st.comparisons
         if k_splits > 1:
-            if n % k_splits == 0:
+            if w_blk.shape[1] % k_splits == 0:
                 # the modeled ring reduce-scatter, then the gather that hands
                 # every chip the combined rows back
                 y_sc = jax.lax.psum_scatter(
@@ -380,18 +388,17 @@ def _shard_map_matmul(x_int, w_int, sx, sw, sharded: ShardedPlacement, cim: CiMC
         return y_sum * sx_ * sw_, conversions, comparisons
 
     in_specs = [P("data", "model"), P("model", None), P(), P(None, None)]
-    args = [x_int, w_int, sx, sw]
     if has_key:
         in_specs.append(P())
-        args.append(key)
-    fn = shard_map(
-        chip_fn,
-        mesh,
-        in_specs=tuple(in_specs),
-        out_specs=(P("data", None), P(), P()),
-        check_rep=False,
+    return jax.jit(
+        jax.shard_map(
+            chip_fn,
+            mesh=mesh,
+            in_specs=tuple(in_specs),
+            out_specs=(P("data", None), P(), P()),
+            check_vma=False,
+        )
     )
-    return fn(*args)
 
 
 def execute_sharded_matmul(

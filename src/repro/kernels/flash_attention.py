@@ -13,7 +13,9 @@ against the full K/V) masks correctly — this is how launch-time prefill uses
 it (models/layers._flash_sharded, perf iteration D).
 
 Scope: Sk·hd·bf16 K/V per (batch, head) must fit VMEM (32k×128 = 8 MiB ✓).
-Validated in interpret mode against ``ref.flash_attention_ref``.
+Validated against ``ref.flash_attention_ref`` in interpret mode (tests) and
+on a TPU v5e (``chip_smoke.py``); ``tests/test_tpu_compile.py`` compiles it
+for a described v5e.
 """
 
 from __future__ import annotations
@@ -36,7 +38,7 @@ def _flash_kernel(
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # (bq, hd)
     sk = k_ref.shape[2]
     n_kv = sk // block_k
-    q_pos = qpos_ref[...].reshape(block_q, 1)  # absolute positions
+    q_pos = qpos_ref[...]  # (bq, 1) absolute positions
 
     if causal:
         # highest kv block intersecting this q tile's causal triangle
@@ -56,8 +58,8 @@ def _flash_kernel(
             pl.dslice(j * block_k, block_k),
             pl.dslice(0, hd),
         )
-        k = pl.load(k_ref, idx)[0, 0].astype(jnp.float32)
-        v = pl.load(v_ref, idx)[0, 0].astype(jnp.float32)
+        k = k_ref[idx][0, 0].astype(jnp.float32)
+        v = v_ref[idx][0, 0].astype(jnp.float32)
         s = q @ k.T  # (bq, bk)
         if causal:
             k_pos = j * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
@@ -121,9 +123,11 @@ def flash_attention_pallas(
             pl.BlockSpec((1, 1, block_q, hd), lambda bb, hh, ii: (bb, hh, ii, 0)),
             pl.BlockSpec((1, 1, sk, hd), lambda bb, hh, ii: (bb, hh // g, 0, 0)),
             pl.BlockSpec((1, 1, sk, hd), lambda bb, hh, ii: (bb, hh // g, 0, 0)),
-            pl.BlockSpec((block_q,), lambda bb, hh, ii: (ii,)),
+            # positions as an (Sq, 1) column: a 1-D block has no layout
+            # Mosaic accepts, and the column needs no in-kernel relayout
+            pl.BlockSpec((block_q, 1), lambda bb, hh, ii: (ii, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda bb, hh, ii: (bb, hh, ii, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, hd), q.dtype),
         interpret=interpret,
-    )(q, k, v, q_positions.astype(jnp.int32))
+    )(q, k, v, q_positions.astype(jnp.int32).reshape(sq, 1))

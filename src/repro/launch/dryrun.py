@@ -29,6 +29,7 @@ from repro.configs.shapes import SHAPES, valid_cells
 from repro.launch import shardings as shmod
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_cell
+from repro.roofline import hw
 from repro.roofline.analysis import roofline
 
 DEFAULT_OUT = Path("results/dryrun")
@@ -52,7 +53,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path, force=F
             mesh = make_production_mesh(multi_pod=multi_pod)
             n_dev = mesh.devices.size
             cell = build_cell(arch, shape_name, mesh)
-            with mesh:
+            with jax.set_mesh(mesh):
                 jitted = jax.jit(
                     cell.fn,
                     in_shardings=cell.in_shardings,
@@ -88,7 +89,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path, force=F
         hlo = compiled.as_text()
 
         rep = roofline(
-            arch, SHAPES[shape_name], cell.cfg, cost, hlo, n_dev, mem_stats
+            arch, SHAPES[shape_name], cell.cfg, cost, hlo, n_dev, mem_stats,
+            device_kind=hw.V5E,
         )
         rec.update(
             status="ok",

@@ -21,6 +21,7 @@ from repro.configs.shapes import SHAPES
 from repro.core.cim_linear import CiMConfig
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_cell
+from repro.roofline import hw
 from repro.roofline.analysis import roofline
 
 OUT = Path("results/hillclimb")
@@ -91,7 +92,7 @@ def run_variant(name: str, force: bool = False):
         mesh = make_production_mesh()
         cfg = _cfg(arch, shape_name, **over)
         cell = build_cell(arch, shape_name, mesh, cfg_override=cfg)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = (
                 jax.jit(cell.fn, in_shardings=cell.in_shardings, donate_argnums=cell.donate)
                 .lower(*cell.args)
@@ -109,7 +110,7 @@ def run_variant(name: str, force: bool = False):
 
         rep = roofline(
             arch, SHAPES[shape_name], cell.cfg, {}, compiled.as_text(),
-            mesh.devices.size, {"bytes": resident},
+            mesh.devices.size, {"bytes": resident}, device_kind=hw.V5E,
         )
         rec.update(
             status="ok",

@@ -4,6 +4,7 @@ this module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_chip_mesh"]
 
@@ -13,12 +14,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = (pod, data, model) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """Mesh whose axes are all ``Auto``: the partitioner propagates shardings
+    and ``with_sharding_constraint`` accepts bare ``PartitionSpec``s."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
     """Degenerate 1x1 mesh over the local device (smoke tests / examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_chip_mesh(data: int = 1, model: int = 1, *, require_concrete: bool = False):
@@ -47,7 +54,7 @@ def make_chip_mesh(data: int = 1, model: int = 1, *, require_concrete: bool = Fa
     n_needed = data * model
     n_have = len(jax.devices())
     if n_have >= n_needed:
-        return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((data, model), ("data", "model"))
     if require_concrete:
         raise RuntimeError(
             f"make_chip_mesh({data}, {model}) needs {n_needed} jax devices but the "
@@ -56,4 +63,6 @@ def make_chip_mesh(data: int = 1, model: int = 1, *, require_concrete: bool = Fa
         )
     from jax.sharding import AbstractMesh
 
-    return AbstractMesh((("data", data), ("model", model)))
+    return AbstractMesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

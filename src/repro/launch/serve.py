@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, reduced
 from repro.configs.registry import get_config
 from repro.core.cim_linear import CiMConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -115,6 +116,7 @@ def serve_batch(
     with obs_trace.span("serve.prefill", batch=b, prompt_len=s):
         cache = model.make_cache(b, total)
         logits, cache = prefill(params, jnp.asarray(prompts), cache)
+        finite = jnp.isfinite(logits).all()
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         jax.block_until_ready(next_tok)
     t_prefill = time.time() - t0
@@ -125,6 +127,7 @@ def serve_batch(
         for i in range(st.gen_len - 1):
             pos = jnp.asarray(s + i, jnp.int32)
             logits, cache = decode(params, next_tok, pos, cache)
+            finite = finite & jnp.isfinite(logits).all()
             next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             out_tokens.append(next_tok)
         jax.block_until_ready(next_tok)
@@ -145,6 +148,7 @@ def serve_batch(
         "prefill_s": t_prefill,
         "decode_s": t_decode,
         "decode_tok_s": b * (st.gen_len - 1) / max(t_decode, 1e-9),
+        "logits_finite": bool(finite),
     }
     if fabric_rollup is not None:
         t = fabric_rollup["totals"]
@@ -305,6 +309,7 @@ def main():
         "(implies --obs-metrics)",
     )
     args = ap.parse_args()
+    use_compile_cache()
 
     with contextlib.ExitStack() as stack:
         if args.obs_log:

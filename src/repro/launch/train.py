@@ -29,6 +29,7 @@ from repro.configs.registry import get_config
 from repro.data.tokens import TokenPipeline
 from repro.ft.watchdog import Watchdog, run_with_restart
 from repro.launch import shardings as sh
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.models import layers as Lmod
@@ -112,7 +113,7 @@ def train(
     )
     opt_init, step_fn = _build_step(model, cfg, st, mesh)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(st.seed))
         opt_state = opt_init(params)
 
@@ -128,14 +129,17 @@ def train(
         ckpt_opt = Checkpointer(Path(st.ckpt_dir) / "opt", st.keep_last)
         wd = Watchdog(Path(st.ckpt_dir) / "heartbeat.json")
         losses = []
+        step_s = []
         t0 = time.time()
         end = min(st.steps, stop_at) if stop_at is not None else st.steps
         for step in range(start, end):
+            t_step = time.time()
             batch = jax.tree.map(jnp.asarray, pipe.batch(step))
             params, opt_state, mets = step_fn(
                 params, opt_state, batch, jnp.asarray(step, jnp.int32)
             )
-            loss = float(mets["loss"])
+            loss = float(mets["loss"])  # waits for the step
+            step_s.append(time.time() - t_step)
             losses.append(loss)
             wd.step(step, {"loss": loss})
             if step % st.log_every == 0 or step == st.steps - 1:
@@ -149,6 +153,7 @@ def train(
         "final_loss": losses[-1],
         "first_loss": losses[0],
         "losses": losses,
+        "step_s": step_s,
         "wall_s": time.time() - t0,
         "params": params,
     }
@@ -166,6 +171,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="results/ckpt")
     ap.add_argument("--max-restarts", type=int, default=2)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

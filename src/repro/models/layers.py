@@ -217,7 +217,6 @@ def _flash_sharded(q, k, v, cfg: ModelConfig):
     )
 
     if ACT_RULES is not None and "mesh" in ACT_RULES:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = ACT_RULES["mesh"]
@@ -235,7 +234,7 @@ def _flash_sharded(q, k, v, cfg: ModelConfig):
             pos = off + jnp.arange(s_loc, dtype=jnp.int32)
             return call(qs, ks, vs, pos)
 
-        out = shard_map(
+        out = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(
@@ -244,7 +243,7 @@ def _flash_sharded(q, k, v, cfg: ModelConfig):
                 P(bspec, None, None, None),
             ),
             out_specs=P(bspec, None, sspec, None),
-            check_rep=False,
+            check_vma=False,
         )(qh, kh, vh)
     else:
         out = call(qh, kh, vh)

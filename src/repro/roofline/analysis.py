@@ -1,8 +1,10 @@
 """Three-term roofline from the compiled dry-run artifact.
 
-  compute    = dot_FLOPs_per_device    / PEAK_FLOPS
-  memory     = op_bytes_per_device     / HBM_BW
-  collective = wire_bytes_per_device   / ICI_LINK_BW
+  compute    = dot_FLOPs_per_device    / flops_bf16
+  memory     = op_bytes_per_device     / hbm_bw
+  collective = wire_bytes_per_device   / ici_link_bw
+
+with the peaks of the chip ``device_kind`` names (``roofline.hw``).
 
 All three numerators come from the loop-aware HLO analyzer
 (roofline/hlo_stats.py): XLA's ``cost_analysis()`` counts while-loop bodies
@@ -54,6 +56,7 @@ def flash_kernel_flops(cfg, shape) -> float:
 class RooflineReport:
     arch: str
     shape: str
+    device_kind: str
     n_devices: int
     flops_per_device: float  # loop-aware dot flops
     bytes_per_device: float  # loop-aware fusion-level bytes
@@ -80,7 +83,8 @@ class RooflineReport:
     def roofline_fraction(self) -> float:
         """useful-FLOPs time / binding-roofline time: the fraction of the
         roofline-limited step that does model math."""
-        t_useful = (self.model_flops / self.n_devices) / hw.PEAK_FLOPS_BF16
+        peak = hw.peaks(self.device_kind).flops_bf16
+        t_useful = (self.model_flops / self.n_devices) / peak
         return t_useful / self.roofline_time if self.roofline_time > 0 else 0.0
 
 
@@ -103,15 +107,18 @@ def roofline(
     hlo_text: str,
     n_devices: int,
     memory_stats: Optional[dict] = None,
+    *,
+    device_kind: str,
 ) -> RooflineReport:
+    pk = hw.peaks(device_kind)
     st: HloStats = analyze(hlo_text, n_devices)
     flops = st.dot_flops + flash_kernel_flops(cfg, shape) / n_devices
     nbytes = st.op_bytes
     wire = st.collective_total
 
-    t_c = flops / hw.PEAK_FLOPS_BF16
-    t_m = nbytes / hw.HBM_BW
-    t_x = wire / hw.ICI_LINK_BW
+    t_c = flops / pk.flops_bf16
+    t_m = nbytes / pk.hbm_bw
+    t_x = wire / pk.ici_link_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
 
@@ -121,6 +128,7 @@ def roofline(
     return RooflineReport(
         arch=arch,
         shape=shape.name,
+        device_kind=device_kind,
         n_devices=n_devices,
         flops_per_device=flops,
         bytes_per_device=nbytes,

@@ -81,3 +81,17 @@ def test_decode_beyond_window_truncates_attention():
         ld, _ = m.decode_step(p, x[:, -1], jnp.asarray(s - 1), cache)
         outs.append(np.asarray(ld))
     np.testing.assert_allclose(outs[0], outs[1], atol=2e-4)
+
+
+def test_compile_cache_follows_the_environment(monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing is set; else the fixed
+    checkout path."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    repo = compile_cache.CHECKOUT_CACHE.parent
+    assert compile_cache.CHECKOUT_CACHE.name == ".jax_cache"
+    assert (repo / "src" / "repro").is_dir()

@@ -109,3 +109,11 @@ def test_cache_shardings_seq_parallel_fallback(mesh):
     # single-device mesh: everything divides; just check it runs
     shd = sh.cache_shardings(mesh, cache_sds, cfg)
     assert hasattr(shd["k"], "spec")
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from repro.roofline import hw
+
+    assert hw.peaks("TPU v5 lite").flops_bf16 == 197e12
+    with pytest.raises(KeyError, match="no peaks"):
+        hw.peaks("cpu")

@@ -64,14 +64,23 @@ REPO = Path(__file__).resolve().parent.parent
 SMOKE_BUDGET_S = 30.0
 # tier-1 test-count floor: suites can grow but cannot silently shrink (a
 # collection error or an importorskip'd-away file drops dozens at once)
-TIER1_MIN_PASSED = 295
+TIER1_MIN_PASSED = 315
+
+
+def _child_env(*paths: Path) -> dict:
+    """Environment of a child process: ``paths`` prepended to PYTHONPATH and
+    JAX pinned to the CPU. This parent imports JAX itself; on a TPU host it
+    holds the chip, and a child that reached for it would fail or hang."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(p) for p in paths] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
+    )
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 def run_tier1() -> bool:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(REPO / "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
+    env = _child_env(REPO / "src")
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q"], cwd=REPO, env=env,
         capture_output=True, text=True,
@@ -121,10 +130,7 @@ def _run_forced_device_smoke(flag: str) -> dict:
     """Run a benchmarks.fabric_sweep smoke under forced 8 host devices
     (subprocess: jax pins the device count at first init, so the in-process
     smokes above cannot change it)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + str(REPO) + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
+    env = _child_env(REPO / "src", REPO)
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
