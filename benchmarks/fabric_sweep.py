@@ -60,8 +60,6 @@ def sweep_points(
     from repro.fabric.pipeline import fabric_throughput, iso_area_comparison
     from repro.fabric.topology import FabricConfig
 
-    from repro.obs import trace as obs_trace
-
     points = []
     for mode in modes:
         for bits in bit_range:
@@ -70,12 +68,8 @@ def sweep_points(
                 fb = FabricConfig(
                     mode=mode, adc_bits=bits, flash_bits=flash_bits, n_arrays=n_arrays
                 )
-                with obs_trace.span(
-                    "fabric.sweep.point", mode=mode, adc_bits=bits,
-                    n_arrays=fb.resolved_n_arrays(),
-                ):
-                    tp = fabric_throughput(fb)
-                    iso = iso_area_comparison(fb)
+                tp = fabric_throughput(fb)
+                iso = iso_area_comparison(fb)
                 points.append(
                     {
                         "mode": mode,
@@ -111,8 +105,6 @@ def shard_sweep_points(
     from repro.fabric.shard import shard_model
     from repro.fabric.topology import ChipMeshConfig, FabricConfig
 
-    from repro.obs import trace as obs_trace
-
     cfg = get_config("smollm-135m")
     points = []
     for data, model in meshes:
@@ -120,9 +112,8 @@ def shard_sweep_points(
             data=data, model=model, fabric=FabricConfig(mode=mode, n_arrays=n_arrays)
         )
         t0 = time.perf_counter()
-        with obs_trace.span("fabric.sweep.shard_point", mesh=f"{data}x{model}"):
-            sps = shard_model(cfg, cm, tokens=tokens, block_only=True)
-            rep = sharded_fabric_report(sps, cm)
+        sps = shard_model(cfg, cm, tokens=tokens, block_only=True)
+        rep = sharded_fabric_report(sps, cm)
         wall = time.perf_counter() - t0
         t = rep["totals"]
         points.append(

@@ -42,6 +42,7 @@ from repro.core.adc import (
 )
 from repro.core.cim_array import bit_planes, plane_weights
 from repro.core.mav_stats import analytic_code_pmf
+from repro.obs import scopes
 
 __all__ = ["CiMConfig", "CimStats", "cim_matmul", "cim_linear", "quantize_symmetric"]
 
@@ -142,7 +143,8 @@ def _bitplane_matmul(x_int, w_int, cfg: CiMConfig, key, row_offset=0):
     wb = wb.reshape(cfg.w_bits, t, r, n).astype(jnp.float32)
 
     # analog MAV of every (plane_a, plane_w, tile): (A, W, M, T, N) in [0,1]
-    mav = jnp.einsum("amtr,btrn->abmtn", xb, wb) / r
+    with jax.named_scope(scopes.CIM_MAC):
+        mav = jnp.einsum("amtr,btrn->abmtn", xb, wb) / r
     # half-LSB bias (standard comparator/DAC offset) so the discrete MAV
     # levels k/R sit mid-bin instead of exactly on code boundaries — without
     # it, arbitrarily small comparator noise flips boundary codes at p=0.5
@@ -150,20 +152,21 @@ def _bitplane_matmul(x_int, w_int, cfg: CiMConfig, key, row_offset=0):
 
     adc_cfg = cfg.adc_config()
     tree = cfg.search_tree()
-    if key is None:
-        res: ADCResult = convert(mav, adc_cfg, key=None, tree=tree)
-    else:
-        mismatch_key, cmp_key = jax.random.split(key)
-        ladder = make_reference_ladder(adc_cfg, mismatch_key)
-        row_ids = jnp.asarray(row_offset, jnp.int32) + jnp.arange(m, dtype=jnp.int32)
-        row_keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(cmp_key, row_ids)
-        res = jax.vmap(
-            lambda v_row, k_row: convert(
-                v_row, adc_cfg, key=k_row, tree=tree, ladder=ladder
-            ),
-            in_axes=(2, 0),
-            out_axes=2,
-        )(mav, row_keys)
+    with jax.named_scope(scopes.CIM_ADC):
+        if key is None:
+            res: ADCResult = convert(mav, adc_cfg, key=None, tree=tree)
+        else:
+            mismatch_key, cmp_key = jax.random.split(key)
+            ladder = make_reference_ladder(adc_cfg, mismatch_key)
+            row_ids = jnp.asarray(row_offset, jnp.int32) + jnp.arange(m, dtype=jnp.int32)
+            row_keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(cmp_key, row_ids)
+            res = jax.vmap(
+                lambda v_row, k_row: convert(
+                    v_row, adc_cfg, key=k_row, tree=tree, ladder=ladder
+                ),
+                in_axes=(2, 0),
+                out_axes=2,
+            )(mav, row_keys)
     # floor reconstruction: digital output is the raw code scaled by one LSB,
     # zero-bias on empty tiles and exact whenever 2^adc_bits >= 2*rows
     v_hat = res.codes.astype(jnp.float32) / (1 << cfg.adc_bits) * adc_cfg.vdd
@@ -173,7 +176,8 @@ def _bitplane_matmul(x_int, w_int, cfg: CiMConfig, key, row_offset=0):
 
     wa = jnp.asarray(plane_weights(cfg.a_bits, cfg.a_signed), jnp.float32)
     ww = jnp.asarray(plane_weights(cfg.w_bits, cfg.w_signed), jnp.float32)
-    y_int = jnp.einsum("abmtn,a,b->mn", counts, wa, ww)
+    with jax.named_scope(scopes.CIM_MAC):
+        y_int = jnp.einsum("abmtn,a,b->mn", counts, wa, ww)
     stats = CimStats(
         conversions=jnp.asarray(mav.size, jnp.int32),
         comparisons=res.comparisons.astype(jnp.float32).sum().astype(jnp.int32),
@@ -199,14 +203,14 @@ def _fake_quant_matmul(x_int, w_int, cfg: CiMConfig):
     x_int, w_int, t = _pad_reduction(x_int, w_int, r)
     xt = x_int.reshape(m, t, r)
     wt = w_int.reshape(t, r, n)
-    partial = jnp.einsum("mtr,trn->mtn", xt, wt)  # (M, T, N) integer-valued
-
     wa = plane_weights(cfg.a_bits, cfg.a_signed)
     ww = plane_weights(cfg.w_bits, cfg.w_signed)
     rms = float(np.sqrt((wa**2).sum()) * np.sqrt((ww**2).sum()))
     step = (r / (1 << cfg.adc_bits)) * rms
-    q = jnp.round(partial / step) * step
-    return q.sum(axis=1), step
+    with jax.named_scope(scopes.CIM_TILES):
+        partial = jnp.einsum("mtr,trn->mtn", xt, wt)  # (M, T, N) integer-valued
+        q = jnp.round(partial / step) * step
+        return q.sum(axis=1), step
 
 
 # ---------------------------------------------------------------------------
@@ -261,8 +265,9 @@ def cim_matmul(
     k = x.shape[-1]
     xm = x.reshape(-1, k)
 
-    x_int, sx = quantize_symmetric(xm, cfg.a_bits, cfg.a_signed)
-    w_int, sw = quantize_symmetric(w, cfg.w_bits, cfg.w_signed, per_axis=-1)
+    with jax.named_scope(scopes.CIM_QUANTIZE):
+        x_int, sx = quantize_symmetric(xm, cfg.a_bits, cfg.a_signed)
+        w_int, sw = quantize_symmetric(w, cfg.w_bits, cfg.w_signed, per_axis=-1)
 
     stats = None
     if cfg.mode == "bitplane":
@@ -272,8 +277,9 @@ def cim_matmul(
     y_q = y_int * sx * sw  # sw broadcasts (1, N)
 
     if cfg.ste:
-        y_lin = xm @ w
-        y_q = y_lin + jax.lax.stop_gradient(y_q - y_lin)
+        with jax.named_scope(scopes.CIM_STE):
+            y_lin = xm @ w
+            y_q = y_lin + jax.lax.stop_gradient(y_q - y_lin)
 
     y = y_q.reshape(*batch_shape, w.shape[1])
     if return_stats:

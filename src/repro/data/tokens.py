@@ -16,6 +16,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
+
 __all__ = ["TokenPipeline"]
 
 
@@ -31,6 +33,10 @@ class TokenPipeline:
 
     def batch(self, step: int, dp_rank: int = 0, dp_size: int = 1) -> dict:
         """Batch shard for one data-parallel rank at one step (numpy)."""
+        with obs_trace.span("data.batch", step=step):
+            return self._batch(step, dp_rank, dp_size)
+
+    def _batch(self, step: int, dp_rank: int, dp_size: int) -> dict:
         assert self.global_batch % dp_size == 0
         local = self.global_batch // dp_size
         rng = np.random.default_rng(

@@ -37,8 +37,6 @@ from repro.core.cim_linear import (
 from repro.fabric.mapper import LayerPlacement, map_matmul
 from repro.fabric.tiles import analytic_cim_stats, column_tile_matmul
 from repro.fabric.topology import FabricConfig
-from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 
 __all__ = ["execute_matmul", "execute_linear"]
 
@@ -88,60 +86,45 @@ def execute_matmul(
             f"placement is for K={placement.k},N={placement.n}; got K={k},N={n}"
         )
 
-    # observability: host-side analytic accounting only (placement counts are
-    # Python ints) — never reads traced values, so metrics cannot perturb
-    # the compiled computation
-    if obs_metrics.active():
-        obs_metrics.inc("fabric_matmuls_total", help="Mapped matmuls executed.")
-        obs_metrics.inc(
-            "fabric_conversions_total",
-            cim.a_bits * cim.w_bits * xm.shape[0] * placement.k_tiles * n,
-            help="Analytic ADC conversions per executed matmul "
-            "(planes x rows x k-tiles x columns).",
-        )
-    with obs_trace.span(
-        "fabric.execute.matmul",
-        layer=placement.name, m=xm.shape[0], k=k, n=n, mode=cim.mode,
-    ):
-        # fabric-level quantization: identical to the unmapped op's front-end
-        x_int, sx = quantize_symmetric(xm, cim.a_bits, cim.a_signed)
-        w_int, sw = quantize_symmetric(w, cim.w_bits, cim.w_signed, per_axis=-1)
+    # fabric-level quantization: identical to the unmapped op's front-end
+    x_int, sx = quantize_symmetric(xm, cim.a_bits, cim.a_signed)
+    w_int, sw = quantize_symmetric(w, cim.w_bits, cim.w_signed, per_axis=-1)
 
-        cols = fabric.cols
-        if cim.mode == "fake_quant" and use_kernel:
-            from repro.kernels.ops import cim_matmul_op
+    cols = fabric.cols
+    if cim.mode == "fake_quant" and use_kernel:
+        from repro.kernels.ops import cim_matmul_op
 
-            # the fused kernel re-derives the same per-tensor / per-column
-            # scales from the float operands and applies them itself
-            parts = []
-            for nt in range(placement.n_tiles):
-                n0, n1 = nt * cols, min((nt + 1) * cols, n)
-                parts.append(
-                    cim_matmul_op(
-                        xm,
-                        w[:, n0:n1],
-                        rows=cim.rows,
-                        adc_bits=cim.adc_bits,
-                        mode="fake_quant",
-                        a_bits=cim.a_bits,
-                        w_bits=cim.w_bits,
-                        a_signed=cim.a_signed,
-                        w_signed=cim.w_signed,
-                    )
+        # the fused kernel re-derives the same per-tensor / per-column
+        # scales from the float operands and applies them itself
+        parts = []
+        for nt in range(placement.n_tiles):
+            n0, n1 = nt * cols, min((nt + 1) * cols, n)
+            parts.append(
+                cim_matmul_op(
+                    xm,
+                    w[:, n0:n1],
+                    rows=cim.rows,
+                    adc_bits=cim.adc_bits,
+                    mode="fake_quant",
+                    a_bits=cim.a_bits,
+                    w_bits=cim.w_bits,
+                    a_signed=cim.a_signed,
+                    w_signed=cim.w_signed,
                 )
-            y_q = jnp.concatenate(parts, axis=1)
-            # the kernel path performs the same tiles x plane-pairs x columns of
-            # conversions as the faithful path — count them analytically
-            stats = analytic_cim_stats(cim, xm.shape[0], placement.k_tiles, n)
-            conversions, comparisons = stats.conversions, stats.comparisons
-        else:
-            y_int, stats = column_tile_matmul(x_int, w_int, cim, cols, key=key)
-            conversions, comparisons = stats.conversions, stats.comparisons
-            y_q = y_int * sx * sw
+            )
+        y_q = jnp.concatenate(parts, axis=1)
+        # the kernel path performs the same tiles x plane-pairs x columns of
+        # conversions as the faithful path — count them analytically
+        stats = analytic_cim_stats(cim, xm.shape[0], placement.k_tiles, n)
+        conversions, comparisons = stats.conversions, stats.comparisons
+    else:
+        y_int, stats = column_tile_matmul(x_int, w_int, cim, cols, key=key)
+        conversions, comparisons = stats.conversions, stats.comparisons
+        y_q = y_int * sx * sw
 
-        if cim.ste:
-            y_lin = xm @ w
-            y_q = y_lin + jax.lax.stop_gradient(y_q - y_lin)
+    if cim.ste:
+        y_lin = xm @ w
+        y_q = y_lin + jax.lax.stop_gradient(y_q - y_lin)
 
     y = y_q.reshape(*batch_shape, n)
     if return_stats:

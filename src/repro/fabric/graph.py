@@ -76,6 +76,7 @@ from repro.fabric.shard import (
 from repro.fabric.tiles import column_tile_matmul
 from repro.fabric.topology import ChipMeshConfig
 from repro.launch.mesh import make_chip_mesh
+from repro.obs import scopes
 from repro.obs import trace as obs_trace
 from repro.obs.fallback import REASON_RAGGED_BATCH, record_fallback
 from repro.fabric.program import _record_request, _record_request_fallback
@@ -443,6 +444,18 @@ class GraphProgram:
             ci = jax.lax.axis_index("model")
             b_loc, s = x_blk.shape[0], x_blk.shape[1]
 
+            @jax.named_scope(scopes.FABRIC_REQUANT)
+            def requant(h):
+                """A matmul input's global absmax scale and integer codes."""
+                absval = jnp.abs(h) if cim.a_signed else jnp.maximum(h, 0)
+                absmax = jnp.max(absval)
+                if collectives:
+                    # max of shard maxes IS the global max, exactly
+                    absmax = jax.lax.pmax(absmax, ("data", "model"))
+                scale = jnp.where(absmax > 0, absmax / qmax_f, 1.0)
+                x_int = jnp.clip(jnp.round(h / scale), lo, qmax)
+                return x_int.reshape(-1, x_int.shape[-1]), scale
+
             def run_nodes(nodes, vals, params, mm_idx0, conversions, comparisons):
                 """ONE interpreter for a node list — the unrolled program,
                 the scanned block body, and the out-of-scan tail all execute
@@ -456,80 +469,71 @@ class GraphProgram:
                 # sibling branches share their producer's quantization
                 mm_idx = 0
                 for node in nodes:
-                    if node.op == "matmul":
-                        src = node.inputs[0]
-                        if src not in qcache:
-                            h = vals[src]
-                            absval = jnp.abs(h) if cim.a_signed else jnp.maximum(h, 0)
-                            absmax = jnp.max(absval)
-                            if collectives:
-                                # max of shard maxes IS the global max, exactly
-                                absmax = jax.lax.pmax(absmax, ("data", "model"))
-                            scale = jnp.where(absmax > 0, absmax / qmax_f, 1.0)
-                            x_int = jnp.clip(jnp.round(h / scale), lo, qmax)
-                            qcache[src] = (x_int.reshape(-1, x_int.shape[-1]), scale)
-                        x_int2, scale = qcache[src]
-                        w_blk, sw_blk = params[node.name]
-                        nkey = (
-                            jax.random.fold_in(key, mm_idx0 + mm_idx)
-                            if has_key else None
-                        )
-                        # K-shard index only: data chips are distinguished by
-                        # the global row ids (row_offset), so each row's noise
-                        # draws are invariant to the batch size and data split
-                        chip_key = _chip_noise_key(nkey, ci) if has_key else None
-                        y_int, st = column_tile_matmul(
-                            x_int2, w_blk, cim, cols, key=chip_key,
-                            row_offset=di * x_int2.shape[0],
-                        )
-                        conversions = conversions + st.conversions
-                        comparisons = comparisons + st.comparisons
-                        if node.combine == "scatter":
-                            if C > 1:
+                    with jax.named_scope(scopes.fabric_op(node.op)):
+                        if node.op == "matmul":
+                            src = node.inputs[0]
+                            if src not in qcache:
+                                qcache[src] = requant(vals[src])
+                            x_int2, scale = qcache[src]
+                            w_blk, sw_blk = params[node.name]
+                            nkey = (
+                                jax.random.fold_in(key, mm_idx0 + mm_idx)
+                                if has_key else None
+                            )
+                            # K-shard index only: data chips are distinguished by
+                            # the global row ids (row_offset), so each row's noise
+                            # draws are invariant to the batch size and data split
+                            chip_key = _chip_noise_key(nkey, ci) if has_key else None
+                            y_int, st = column_tile_matmul(
+                                x_int2, w_blk, cim, cols, key=chip_key,
+                                row_offset=di * x_int2.shape[0],
+                            )
+                            conversions = conversions + st.conversions
+                            comparisons = comparisons + st.comparisons
+                            if node.combine == "scatter":
+                                if C > 1:
+                                    if collectives:
+                                        # the combine that leaves chip ci holding its
+                                        # tile-aligned K-slice of the consumer
+                                        y_int = jax.lax.psum_scatter(
+                                            y_int, "model", scatter_dimension=1, tiled=True
+                                        )
+                                    else:
+                                        nc = y_int.shape[1] // C
+                                        y_int = jax.lax.dynamic_slice_in_dim(
+                                            y_int, ci * nc, nc, axis=1
+                                        )
+                            else:  # psum: the router's full replicated output
                                 if collectives:
-                                    # the combine that leaves chip ci holding its
-                                    # tile-aligned K-slice of the consumer
-                                    y_int = jax.lax.psum_scatter(
-                                        y_int, "model", scatter_dimension=1, tiled=True
-                                    )
-                                else:
-                                    nc = y_int.shape[1] // C
-                                    y_int = jax.lax.dynamic_slice_in_dim(
-                                        y_int, ci * nc, nc, axis=1
-                                    )
-                        else:  # psum: the router's full replicated output
+                                    y_int = jax.lax.psum(y_int, "model")
+                            y = y_int * scale * sw_blk * one_f  # one_f: no FMA across
+                            # the CiM boundary; mask_blk re-zeroes pad rows the
+                            # noisy ADC lifted off zero (see chip_fn comment)
+                            vals[node.name] = y.reshape(b_loc, s, -1) * mask_blk
+                            mm_idx += 1
+                        elif node.op == "norm":
+                            h = vals[node.inputs[0]]
+                            sumsq = jnp.sum(h * h, axis=-1, keepdims=True)
                             if collectives:
-                                y_int = jax.lax.psum(y_int, "model")
-                        y = y_int * scale * sw_blk * one_f  # one_f: no FMA across
-                        # the CiM boundary; mask_blk re-zeroes pad rows the
-                        # noisy ADC lifted off zero (see chip_fn comment)
-                        vals[node.name] = y.reshape(b_loc, s, -1) * mask_blk
-                        mm_idx += 1
-                    elif node.op == "norm":
-                        h = vals[node.inputs[0]]
-                        sumsq = jnp.sum(h * h, axis=-1, keepdims=True)
-                        if collectives:
-                            sumsq = jax.lax.psum(sumsq, "model")
-                        vals[node.name] = _norm_apply(
-                            h, params[node.name], node.eps, node.d * one_f, sumsq
-                        )
-                    elif node.op == "attention":
-                        q, k_, v_ = (vals[nm] for nm in node.inputs)
-                        vals[node.name] = _attention_mix(
-                            q, k_, v_, node.n_heads // C, node.n_kv_heads // C,
-                            node.head_dim,
-                        )
-                    elif node.op == "silu_gate":
-                        vals[node.name] = _silu_gate(*(vals[nm] for nm in node.inputs))
-                    elif node.op == "residual":
-                        a, b = (vals[nm] for nm in node.inputs)
-                        vals[node.name] = a + b
-                    elif node.op == "moe_gate":
-                        expert, router = (vals[nm] for nm in node.inputs)
-                        # one_f: the gated product feeds a residual add — see above
-                        vals[node.name] = expert * _expert0_prob(router) * one_f
-                    else:  # pragma: no cover — taxonomy is closed in the mapper
-                        raise ValueError(f"unknown graph op {node.op!r}")
+                                sumsq = jax.lax.psum(sumsq, "model")
+                            vals[node.name] = _norm_apply(
+                                h, params[node.name], node.eps, node.d * one_f, sumsq
+                            )
+                        elif node.op == "attention":
+                            q, k_, v_ = (vals[nm] for nm in node.inputs)
+                            vals[node.name] = _attention_mix(
+                                q, k_, v_, node.n_heads // C, node.n_kv_heads // C,
+                                node.head_dim,
+                            )
+                        elif node.op == "silu_gate":
+                            vals[node.name] = _silu_gate(*(vals[nm] for nm in node.inputs))
+                        elif node.op == "residual":
+                            a, b = (vals[nm] for nm in node.inputs)
+                            vals[node.name] = a + b
+                        elif node.op == "moe_gate":
+                            expert, router = (vals[nm] for nm in node.inputs)
+                            # one_f: the gated product feeds a residual add — see above
+                            vals[node.name] = expert * _expert0_prob(router) * one_f
                 return vals, conversions, comparisons
 
             conversions = jnp.zeros((), jnp.int32)
@@ -748,14 +752,15 @@ class GraphProgram:
                 self.placements, self.chip_mesh, self.cim,
                 key=key, backend="sequential", return_stats=return_stats,
             )
-        flat = self._prepare(x, weights, key, real_rows=real_rows)
+        with obs_trace.span("fabric.graph.prepare"):
+            flat = self._prepare(x, weights, key, real_rows=real_rows)
         rows = b if real_rows is None else real_rows
         _record_request("fabric.graph", self, rows * x.shape[1], fused=True)
         with obs_trace.span(
             "fabric.graph.forward", n_matmuls=self.n_layers,
             mesh=f"{self.chip_mesh.data}x{self.chip_mesh.model}",
             tokens=rows * x.shape[1],
-        ), obs_trace.annotate("fabric.graph.fused"):
+        ):
             y, conversions, comparisons = self._fused(key is not None)(x, *flat)
         if real_rows is not None:
             y = y[:real_rows]

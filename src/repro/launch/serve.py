@@ -15,6 +15,7 @@ import argparse
 import contextlib
 import dataclasses
 import functools
+import itertools
 import time
 from typing import Optional
 
@@ -31,6 +32,9 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 __all__ = ["ServeSettings", "serve_batch", "parse_fabric_mesh", "compiled_model"]
+
+# each served batch's id, shared by its spans
+_BATCH_IDS = itertools.count()
 
 
 @functools.lru_cache(maxsize=8)
@@ -111,27 +115,30 @@ def serve_batch(
         prompts = rng.integers(0, cfg.vocab, (st.batch, st.prompt_len)).astype(np.int32)
     b, s = prompts.shape
     total = s + st.gen_len
+    batch_id = next(_BATCH_IDS)
 
-    t0 = time.time()
-    with obs_trace.span("serve.prefill", batch=b, prompt_len=s):
-        cache = model.make_cache(b, total)
+    t0 = time.perf_counter()
+    with obs_trace.span("serve.prefill", batch=batch_id, rows=b, prompt_len=s):
+        with obs_trace.span("serve.make_cache", batch=batch_id):
+            cache = model.make_cache(b, total)
         logits, cache = prefill(params, jnp.asarray(prompts), cache)
         finite = jnp.isfinite(logits).all()
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         jax.block_until_ready(next_tok)
-    t_prefill = time.time() - t0
+    t_prefill = time.perf_counter() - t0
 
     out_tokens = [next_tok]
-    t0 = time.time()
-    with obs_trace.span("serve.decode", batch=b, gen_len=st.gen_len):
+    t0 = time.perf_counter()
+    with obs_trace.span("serve.decode", batch=batch_id, rows=b, gen_len=st.gen_len):
         for i in range(st.gen_len - 1):
-            pos = jnp.asarray(s + i, jnp.int32)
-            logits, cache = decode(params, next_tok, pos, cache)
-            finite = finite & jnp.isfinite(logits).all()
-            next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            out_tokens.append(next_tok)
+            with obs_trace.span("serve.decode_step", batch=batch_id, step=i):
+                pos = jnp.asarray(s + i, jnp.int32)
+                logits, cache = decode(params, next_tok, pos, cache)
+                finite = finite & jnp.isfinite(logits).all()
+                next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                out_tokens.append(next_tok)
         jax.block_until_ready(next_tok)
-    t_decode = time.time() - t0
+    t_decode = time.perf_counter() - t0
 
     obs_metrics.inc("serve_requests_total", b, help="Requests served (batch slots).")
     obs_metrics.observe(
@@ -141,7 +148,8 @@ def serve_batch(
         "serve_decode_seconds", t_decode, help="Batched decode wall time."
     )
 
-    gen = np.stack([np.asarray(t) for t in out_tokens], axis=1)
+    with obs_trace.span("serve.fetch", batch=batch_id):
+        gen = np.stack([np.asarray(t) for t in out_tokens], axis=1)
     out = {
         "prompts": prompts,
         "generated": gen,
@@ -193,7 +201,7 @@ def serve_batch(
             measured = obs_metrics.get_value("fabric_measured_collective_seconds")
             calib = obs_metrics.get_value("fabric_link_clock_calibration")
             obs_trace.event(
-                "serve.request_summary", batch=b, total_tokens=total,
+                "serve.request_summary", batch=batch_id, rows=b, total_tokens=total,
                 fused_requests=fused, fallback_requests=fell,
                 conversions=conv, link_bits=bits,
                 modeled_link_s=modeled, measured_collective_s=measured,

@@ -13,6 +13,7 @@ CLI (CPU-scale example — examples/train_lm.py wraps this):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -33,6 +34,7 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.models import layers as Lmod
+from repro.obs import trace as obs_trace
 from repro.optim import make_optimizer
 from repro.optim.schedules import warmup_cosine
 
@@ -130,16 +132,17 @@ def train(
         wd = Watchdog(Path(st.ckpt_dir) / "heartbeat.json")
         losses = []
         step_s = []
-        t0 = time.time()
+        t0 = time.perf_counter()
         end = min(st.steps, stop_at) if stop_at is not None else st.steps
         for step in range(start, end):
-            t_step = time.time()
-            batch = jax.tree.map(jnp.asarray, pipe.batch(step))
-            params, opt_state, mets = step_fn(
-                params, opt_state, batch, jnp.asarray(step, jnp.int32)
-            )
-            loss = float(mets["loss"])  # waits for the step
-            step_s.append(time.time() - t_step)
+            t_step = time.perf_counter()
+            with obs_trace.span("train.step", step=step):
+                batch = jax.tree.map(jnp.asarray, pipe.batch(step))
+                params, opt_state, mets = step_fn(
+                    params, opt_state, batch, jnp.asarray(step, jnp.int32)
+                )
+                loss = float(mets["loss"])  # waits for the step
+            step_s.append(time.perf_counter() - t_step)
             losses.append(loss)
             wd.step(step, {"loss": loss})
             if step % st.log_every == 0 or step == st.steps - 1:
@@ -154,7 +157,7 @@ def train(
         "first_loss": losses[0],
         "losses": losses,
         "step_s": step_s,
-        "wall_s": time.time() - t0,
+        "wall_s": time.perf_counter() - t0,
         "params": params,
     }
 
@@ -170,6 +173,12 @@ def main():
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--ckpt-dir", default="results/ckpt")
     ap.add_argument("--max-restarts", type=int, default=2)
+    ap.add_argument(
+        "--obs-log",
+        default=None,
+        metavar="PATH",
+        help="stream repro.obs spans (train.step, data.batch) to PATH as JSONL",
+    )
     args = ap.parse_args()
     use_compile_cache()
 
@@ -193,7 +202,10 @@ def main():
         )
         return st.steps
 
-    run_with_restart(run, max_restarts=args.max_restarts)
+    with contextlib.ExitStack() as stack:
+        if args.obs_log:
+            stack.enter_context(obs_trace.tracing(jsonl=args.obs_log))
+        run_with_restart(run, max_restarts=args.max_restarts)
 
 
 if __name__ == "__main__":

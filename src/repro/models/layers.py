@@ -23,6 +23,7 @@ from jax import lax
 
 from repro.configs.base import ModelConfig
 from repro.core.cim_linear import CiMConfig, cim_matmul
+from repro.obs import scopes
 
 _NEG = -1e30
 
@@ -98,12 +99,14 @@ def dense(
     cim: Optional[CiMConfig] = None,
 ):
     """Linear layer; routes through the CiM pipeline when enabled."""
-    if cim is not None and cim.mode != "exact":
-        y = cim_matmul(x, w.astype(jnp.float32), cim).astype(x.dtype)
-    else:
-        y = x @ w.astype(x.dtype)
-    if bias is not None:
-        y = y + bias.astype(y.dtype)
+    on_cim = cim is not None and cim.mode != "exact"
+    with jax.named_scope(scopes.CIM_LINEAR if on_cim else scopes.LINEAR):
+        if on_cim:
+            y = cim_matmul(x, w.astype(jnp.float32), cim).astype(x.dtype)
+        else:
+            y = x @ w.astype(x.dtype)
+        if bias is not None:
+            y = y + bias.astype(y.dtype)
     return y
 
 
@@ -159,6 +162,7 @@ def _rms_norm_bwd(eps, res, dy):
 _rms_norm_fused.defvjp(_rms_norm_fwd, _rms_norm_bwd)
 
 
+@jax.named_scope(scopes.NORM)
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
     if LEGACY_NORM:
         return _rms_norm_legacy(x, scale, eps)
@@ -333,6 +337,7 @@ def _blocked_sdpa(
     return out
 
 
+@jax.named_scope(scopes.ATTENTION)
 def attention(
     p: dict,
     x: jnp.ndarray,  # (B, S, D)
@@ -362,45 +367,50 @@ def attention(
     out = out.astype(x.dtype).reshape(b, s, h * hd)
     out = constrain(out, ("dp", None, "tp"))
     y = constrain(dense(out, p["wo"], None, cim), ("dp", None, None))
-    new_cache = None
-    if cache is not None:
-        sc = cache["k"].shape[1]
-        if cache["k"].dtype == jnp.int8:
-            # int8 KV cache: per-kv-head symmetric scales computed at prefill
-            k_scale = jnp.max(jnp.abs(k.astype(jnp.float32)), axis=(0, 1, 3)) / 127.0
-            v_scale = jnp.max(jnp.abs(v.astype(jnp.float32)), axis=(0, 1, 3)) / 127.0
-            k_scale = jnp.maximum(k_scale, 1e-8)
-            v_scale = jnp.maximum(v_scale, 1e-8)
-            kq = jnp.clip(jnp.round(k.astype(jnp.float32) / k_scale[None, None, :, None]), -127, 127)
-            vq = jnp.clip(jnp.round(v.astype(jnp.float32) / v_scale[None, None, :, None]), -127, 127)
-            k, v = kq.astype(jnp.int8), vq.astype(jnp.int8)
-            scales = {"k_scale": k_scale, "v_scale": v_scale}
-        else:
-            scales = {}
-        if s <= sc:  # prefix fits: write at the front
-            new_cache = {
-                "k": lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)
-                ),
-                "v": lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)
-                ),
-                "pos": lax.dynamic_update_slice(
-                    cache["pos"], positions.astype(jnp.int32), (0,)
-                ),
-                **scales,
-            }
-        else:  # window cache: keep last sc keys, ring-rotated (slot = pos % sc)
-            shift = (s - sc) % sc
-            new_cache = {
-                "k": jnp.roll(k[:, -sc:].astype(cache["k"].dtype), shift, axis=1),
-                "v": jnp.roll(v[:, -sc:].astype(cache["v"].dtype), shift, axis=1),
-                "pos": jnp.roll(positions[-sc:].astype(jnp.int32), shift),
-                **scales,
-            }
-    return y, new_cache
+    if cache is None:
+        return y, None
+    return y, _write_prefill_cache(cache, k, v, positions)
 
 
+@jax.named_scope(scopes.KV_CACHE)
+def _write_prefill_cache(cache: dict, k, v, positions) -> dict:
+    s, sc = k.shape[1], cache["k"].shape[1]
+    if cache["k"].dtype == jnp.int8:
+        # int8 KV cache: per-kv-head symmetric scales computed at prefill
+        k_scale = jnp.max(jnp.abs(k.astype(jnp.float32)), axis=(0, 1, 3)) / 127.0
+        v_scale = jnp.max(jnp.abs(v.astype(jnp.float32)), axis=(0, 1, 3)) / 127.0
+        k_scale = jnp.maximum(k_scale, 1e-8)
+        v_scale = jnp.maximum(v_scale, 1e-8)
+        kq = jnp.clip(jnp.round(k.astype(jnp.float32) / k_scale[None, None, :, None]), -127, 127)
+        vq = jnp.clip(jnp.round(v.astype(jnp.float32) / v_scale[None, None, :, None]), -127, 127)
+        k, v = kq.astype(jnp.int8), vq.astype(jnp.int8)
+        scales = {"k_scale": k_scale, "v_scale": v_scale}
+    else:
+        scales = {}
+    if s <= sc:  # prefix fits: write at the front
+        return {
+            "k": lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)
+            ),
+            "v": lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)
+            ),
+            "pos": lax.dynamic_update_slice(
+                cache["pos"], positions.astype(jnp.int32), (0,)
+            ),
+            **scales,
+        }
+    # window cache: keep last sc keys, ring-rotated (slot = pos % sc)
+    shift = (s - sc) % sc
+    return {
+        "k": jnp.roll(k[:, -sc:].astype(cache["k"].dtype), shift, axis=1),
+        "v": jnp.roll(v[:, -sc:].astype(cache["v"].dtype), shift, axis=1),
+        "pos": jnp.roll(positions[-sc:].astype(jnp.int32), shift),
+        **scales,
+    }
+
+
+@jax.named_scope(scopes.ATTENTION)
 def decode_attention(
     p: dict,
     x: jnp.ndarray,  # (B, 1, D)
@@ -426,19 +436,21 @@ def decode_attention(
     int8_kv = cache["k"].dtype == jnp.int8
     if int8_kv:
         ks, vs = cache["k_scale"], cache["v_scale"]  # (KV,)
-        k_w = jnp.clip(
-            jnp.round(k.astype(jnp.float32) / jnp.maximum(ks, 1e-8)[None, None, :, None]),
-            -127, 127,
-        ).astype(jnp.int8)
-        v_w = jnp.clip(
-            jnp.round(v.astype(jnp.float32) / jnp.maximum(vs, 1e-8)[None, None, :, None]),
-            -127, 127,
-        ).astype(jnp.int8)
-    else:
-        k_w, v_w = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
-    ck = lax.dynamic_update_slice(cache["k"], k_w, (0, slot, 0, 0))
-    cv = lax.dynamic_update_slice(cache["v"], v_w, (0, slot, 0, 0))
-    cpos = lax.dynamic_update_slice(cache["pos"], pos[None].astype(jnp.int32), (slot,))
+    with jax.named_scope(scopes.KV_CACHE):
+        if int8_kv:
+            k_w = jnp.clip(
+                jnp.round(k.astype(jnp.float32) / jnp.maximum(ks, 1e-8)[None, None, :, None]),
+                -127, 127,
+            ).astype(jnp.int8)
+            v_w = jnp.clip(
+                jnp.round(v.astype(jnp.float32) / jnp.maximum(vs, 1e-8)[None, None, :, None]),
+                -127, 127,
+            ).astype(jnp.int8)
+        else:
+            k_w, v_w = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        ck = lax.dynamic_update_slice(cache["k"], k_w, (0, slot, 0, 0))
+        cv = lax.dynamic_update_slice(cache["v"], v_w, (0, slot, 0, 0))
+        cpos = lax.dynamic_update_slice(cache["pos"], pos[None].astype(jnp.int32), (slot,))
 
     valid = (cpos <= pos) & (cpos >= 0)
     if cfg.sliding_window is not None:
@@ -486,6 +498,7 @@ def decode_attention(
     return y, new_cache
 
 
+@jax.named_scope(scopes.KV_CACHE)
 def make_attn_cache(cfg: ModelConfig, batch: int, seq_len: int, n_layers: int):
     """Preallocated KV cache (seq capped to the sliding window if set).
 
@@ -522,6 +535,7 @@ def init_mlp(key, cfg: ModelConfig, n_layers: int, d_ff: Optional[int] = None):
     }
 
 
+@jax.named_scope(scopes.MLP)
 def mlp(p: dict, x: jnp.ndarray, cfg: ModelConfig):
     cim = cfg.cim
     sh = ("dp", None, "tp") if x.ndim == 3 else ("dp", "tp")
@@ -546,6 +560,7 @@ def init_embedding(key, cfg: ModelConfig):
     return p
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(p: dict, tokens_or_x: jnp.ndarray, cfg: ModelConfig):
     if cfg.input_kind == "embeddings":
         return constrain(tokens_or_x.astype(cdtype(cfg)), ("dp", None, None))
@@ -559,6 +574,7 @@ def unembed_weight(p: dict, cfg: ModelConfig):
     return p["unembed"]
 
 
+@jax.named_scope(scopes.LM_HEAD)
 def chunked_xent(
     p: dict,
     h: jnp.ndarray,  # (B, S, D) final hidden states
@@ -598,6 +614,7 @@ def chunked_xent(
     return tot / jnp.maximum(cnt, 1.0)
 
 
+@jax.named_scope(scopes.LM_HEAD)
 def logits_step(p: dict, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """Decode-step logits (B, 1, V): direct matmul, vocab sharded over TP."""
     w = unembed_weight(p, cfg)

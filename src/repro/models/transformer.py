@@ -17,6 +17,7 @@ from jax import lax
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models.moe import init_moe, moe_ffn, moe_ffn_dense
+from repro.obs import scopes
 
 __all__ = [
     "init_transformer",
@@ -74,7 +75,8 @@ def transformer_forward(p: dict, x_in: jnp.ndarray, cfg: ModelConfig):
 
     if cfg.remat != "none":
         body = jax.checkpoint(body)
-    (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)), _layer_params(p, cfg))
+    with jax.named_scope(scopes.LAYER_SCAN):
+        (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)), _layer_params(p, cfg))
     h = L.rms_norm(x, p["ln_f"], cfg.norm_eps)
     return h, aux / max(cfg.n_layers, 1)
 
@@ -100,7 +102,8 @@ def transformer_prefill(p: dict, x_in: jnp.ndarray, cfg: ModelConfig, cache: dic
 
     if cfg.remat != "none":
         body = jax.checkpoint(body)
-    x, new_cache = lax.scan(body, x, (_layer_params(p, cfg), cache))
+    with jax.named_scope(scopes.LAYER_SCAN):
+        x, new_cache = lax.scan(body, x, (_layer_params(p, cfg), cache))
     h = L.rms_norm(x, p["ln_f"], cfg.norm_eps)
     return h, new_cache
 
@@ -125,7 +128,8 @@ def transformer_decode(p: dict, token, cfg: ModelConfig, pos, cache: dict):
             y = L.mlp(lp["mlp"], hn, cfg)
         return x + y, new_cache
 
-    x, new_cache = lax.scan(body, x, (_layer_params(p, cfg), cache))
+    with jax.named_scope(scopes.LAYER_SCAN):
+        x, new_cache = lax.scan(body, x, (_layer_params(p, cfg), cache))
     h = L.rms_norm(x, p["ln_f"], cfg.norm_eps)
     logits = L.logits_step(p["embed"], h, cfg)
     return logits, new_cache

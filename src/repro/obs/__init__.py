@@ -4,8 +4,11 @@ Telemetry in three coordinated pieces, all contextvar-scoped and all
 zero-cost when no observer is active:
 
   * :mod:`repro.obs.trace` — wall-clock spans + point events
-    (``tracing`` / ``span`` / ``event`` / ``annotate``). Host-side only;
-    enabling tracing provably does not change compiled programs.
+    (``tracing`` / ``span`` / ``event``). Host-side only; enabling
+    tracing provably does not change compiled programs. Under a tracer
+    each span is also a ``jax.profiler`` annotation.
+  * :mod:`repro.obs.scopes` — the device scope names
+    (``jax.named_scope``) of the model, the CiM linear and the fabric.
   * :mod:`repro.obs.metrics` — counters / gauges / histograms
     (``collecting`` / ``inc`` / ``set_gauge`` / ``observe``) with
     Prometheus text exposition.
@@ -44,7 +47,7 @@ from repro.obs.metrics import (
     set_gauge,
 )
 from repro.obs.sinks import JsonlSink, read_jsonl, write_prometheus
-from repro.obs.trace import Tracer, annotate, enabled, event, span, tracing
+from repro.obs.trace import Tracer, enabled, event, span, tracing
 
 __all__ = [
     # trace
@@ -53,7 +56,6 @@ __all__ = [
     "span",
     "event",
     "enabled",
-    "annotate",
     # metrics
     "Counter",
     "Gauge",

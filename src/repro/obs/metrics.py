@@ -197,28 +197,6 @@ class MetricsRegistry:
     def names(self) -> List[str]:
         return sorted(self._metrics)
 
-    def snapshot(self) -> dict:
-        """JSON-ready dump: metric name -> kind + labeled samples."""
-        out = {}
-        for name, m in sorted(self._metrics.items()):
-            if m.kind == "histogram":
-                out[name] = {
-                    "kind": m.kind,
-                    "samples": [
-                        {"labels": dict(k), "count": s[2], "sum": s[1]}
-                        for k, s in sorted(m.samples.items())
-                    ],
-                }
-            else:
-                out[name] = {
-                    "kind": m.kind,
-                    "samples": [
-                        {"labels": dict(k), "value": v}
-                        for k, v in sorted(m.samples.items())
-                    ],
-                }
-        return out
-
     def prometheus_text(self) -> str:
         """The Prometheus text exposition of every registered metric."""
         lines: List[str] = []

@@ -11,27 +11,35 @@ Instrumentation is strictly host-side: spans wall-clock Python-level
 work and never touch traced values, so enabling tracing provably cannot
 perturb a compiled program — ``GraphProgram.collective_counts`` and the
 fused logits are asserted bit-identical with tracing on/off in
-``tests/test_obs.py``. The only jax integration is :func:`annotate`,
-which wraps a region in ``jax.profiler.TraceAnnotation`` (a profiler
-timeline label, invisible to jaxprs) when tracing is enabled.
+``tests/test_obs.py``. Under an active tracer each span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler`` trace
+shows the program's spans on the device ops' clock.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import time
 from typing import Iterator, List, Optional
 
+import jax
+
 from repro.obs.sinks import JsonlSink
 
-__all__ = ["Tracer", "tracing", "span", "event", "enabled", "annotate"]
+__all__ = ["Tracer", "tracing", "span", "event", "enabled"]
 
 # Stack of active tracers (innermost last). A ContextVar keeps concurrent
 # threads / async serving tasks from seeing each other's spans.
 _TRACERS: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "obs_tracers", default=()
 )
+# The id of the innermost open span: each record's ``parent``.
+_OPEN: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
+    "obs_open_span", default=None
+)
+_IDS = itertools.count()
 
 
 class Tracer:
@@ -125,15 +133,19 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "_tracers", "_t0")
+    __slots__ = ("name", "attrs", "id", "parent", "_tracers", "_t0", "_token", "_mark")
 
     def __init__(self, name: str, attrs: dict, tracers: tuple):
         self.name = name
         self.attrs = attrs
+        self.id = next(_IDS)
         self._tracers = tracers
-        self._t0 = 0.0
 
     def __enter__(self):
+        self.parent = _OPEN.get()
+        self._token = _OPEN.set(self.id)
+        self._mark = jax.profiler.TraceAnnotation(self.name)
+        self._mark.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -143,9 +155,13 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._mark.__exit__(*exc)
+        _OPEN.reset(self._token)
         record = {
             "kind": "span",
             "name": self.name,
+            "id": self.id,
+            "parent": self.parent,
             "t_s": self._t0,
             "duration_s": t1 - self._t0,
             "attrs": self.attrs,
@@ -159,9 +175,12 @@ def span(name: str, **attrs):
     """A wall-clock span context manager.
 
     With no active tracer this returns one shared no-op singleton (zero
-    allocation, the documented disabled-path cost); with tracers active
-    it records ``{name, t_s, duration_s, attrs}`` to every one of them
-    on exit.
+    allocation, the documented disabled-path cost). With tracers active
+    it opens a ``jax.profiler.TraceAnnotation`` of ``name`` for its
+    duration and records ``{name, id, parent, t_s, duration_s, attrs}``
+    to every one of them on exit: ``id`` is unique in the process and
+    ``parent`` is the id of the innermost span open around it (``None``
+    at the top).
 
     Example::
 
@@ -171,6 +190,13 @@ def span(name: str, **attrs):
         ...         sp.set(tiles=4)
         >>> tr.spans[0]["attrs"]
         {'layer': 'q_proj', 'tiles': 4}
+        >>> with tracing() as tr:
+        ...     with span("serve.decode"):
+        ...         with span("serve.decode_step"):
+        ...             pass
+        >>> inner, outer = tr.spans
+        >>> inner["parent"] == outer["id"], outer["parent"]
+        (True, None)
     """
     tracers = _TRACERS.get()
     if not tracers:
@@ -199,27 +225,10 @@ def event(name: str, **attrs) -> None:
     record = {
         "kind": "event",
         "name": name,
+        "parent": _OPEN.get(),
         "t_s": time.perf_counter(),
         "attrs": attrs,
     }
     for tr in tracers:
         tr._emit(record)
 
-
-def annotate(name: str):
-    """A ``jax.profiler.TraceAnnotation`` for ``name`` when tracing is
-    enabled, else a null context — the hook that labels the fused
-    shard_map programs in ``jax.profiler`` timelines without touching
-    their jaxprs (profiler annotations are host-side timeline markers).
-
-    Example::
-
-        >>> from repro.obs import annotate
-        >>> with annotate("fabric.graph.fused"):
-        ...     pass  # dispatch the fused program here
-    """
-    if not _TRACERS.get():
-        return contextlib.nullcontext()
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

@@ -7,6 +7,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 __all__ = ["AdamWState", "adamw_init", "adamw_update"]
 
 
@@ -25,6 +27,7 @@ def adamw_init(params) -> AdamWState:
     )
 
 
+@jax.named_scope(scopes.OPTIMIZER)
 def adamw_update(
     grads,
     state: AdamWState,

@@ -75,6 +75,86 @@ def test_tracing_records_spans_events_and_nesting_composes():
     assert not obs.enabled()
 
 
+def test_span_opens_a_profiler_annotation_only_under_a_tracer(monkeypatch):
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    with obs.span("serve.fetch"):
+        pass
+    assert opened == []
+    with obs.tracing():
+        with obs.span("serve.decode"):
+            with obs.span("serve.decode_step", step=0):
+                pass
+    assert opened == [("enter", "serve.decode"), ("enter", "serve.decode_step"),
+                      ("exit", "serve.decode_step"), ("exit", "serve.decode")]
+
+
+def test_span_records_parent_and_id():
+    with obs.tracing() as tr:
+        with obs.span("serve.prefill", batch=3):
+            with obs.span("serve.make_cache", batch=3):
+                pass
+            obs.event("inside")
+        with obs.span("serve.fetch", batch=3):
+            pass
+    make, prefill, fetch = tr.spans
+    assert prefill["parent"] is None and fetch["parent"] is None
+    assert make["parent"] == prefill["id"]
+    assert len({make["id"], prefill["id"], fetch["id"]}) == 3
+    assert tr.events[0]["parent"] == prefill["id"]
+    assert {s["attrs"]["batch"] for s in tr.spans} == {3}
+
+
+def test_serve_batch_spans_share_the_batch_id():
+    from repro.configs import ARCHS, reduced
+    from repro.launch.serve import ServeSettings, serve_batch
+
+    cfg = reduced(ARCHS["smollm-135m"], n_layers=1)
+    with obs.tracing() as tr:
+        serve_batch(cfg, ServeSettings(batch=2, prompt_len=8, gen_len=4))
+    names = [s["name"] for s in tr.spans]
+    assert names == ["serve.make_cache", "serve.prefill", *["serve.decode_step"] * 3,
+                     "serve.decode", "serve.fetch"]
+    assert len({s["attrs"]["batch"] for s in tr.spans}) == 1
+    by = {s["name"]: s for s in tr.spans}
+    assert by["serve.make_cache"]["parent"] == by["serve.prefill"]["id"]
+    steps = [s for s in tr.spans if s["name"] == "serve.decode_step"]
+    assert [s["attrs"]["step"] for s in steps] == [0, 1, 2]
+    assert {s["parent"] for s in steps} == {by["serve.decode"]["id"]}
+
+
+def test_train_step_spans_hold_the_data_batch(tmp_path):
+    from repro.configs import ARCHS, reduced
+    from repro.launch.train import TrainSettings, train
+    from repro.models import layers
+
+    cfg = reduced(ARCHS["smollm-135m"], n_layers=1, d_model=32, vocab=64, n_heads=2,
+                  n_kv_heads=1, d_ff=64, head_dim=16)
+    st = TrainSettings(steps=2, batch=2, seq=16, warmup=1, ckpt_dir=str(tmp_path),
+                       ckpt_every=100, log_every=100)
+    try:
+        with obs.tracing() as tr:
+            train(cfg, st)
+    finally:
+        layers.set_act_rules(None)
+    steps = [s for s in tr.spans if s["name"] == "train.step"]
+    feeds = [s for s in tr.spans if s["name"] == "data.batch"]
+    assert [s["attrs"]["step"] for s in steps] == [0, 1]
+    assert [f["parent"] for f in feeds] == [s["id"] for s in steps]
+    assert [f["attrs"]["step"] for f in feeds] == [0, 1]
+
+
 def test_disabled_span_overhead_is_bounded():
     """The disabled path must stay cheap enough to leave in hot loops."""
     t0 = time.perf_counter()
@@ -300,7 +380,8 @@ def test_obs_does_not_change_fused_graph_logits_1x1_noisy():
         y_on = np.asarray(prog(x, ws, key=nk))
     assert census_on == census_off
     assert (y_on == y_off).all()
-    assert any(s["name"] == "fabric.graph.forward" for s in tr.spans)
+    names = [s["name"] for s in tr.spans]
+    assert names == ["fabric.graph.prepare", "fabric.graph.forward"]
 
 
 # ---------------------------------------------------------------------------
