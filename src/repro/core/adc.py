@@ -19,7 +19,9 @@ dependent noise injected via ``core.noise``.
 
 All converters return ``ADCResult(codes, comparisons, cycles)`` where
 ``comparisons`` counts comparator firings (energy) and ``cycles`` counts
-sequential comparison cycles (latency).
+sequential comparison cycles (latency). Noiseless converters are resolved by
+one compare against every ladder boundary (``takes_direct_path``); the
+search-tree walk runs where comparator noise makes the path matter.
 """
 
 from __future__ import annotations
@@ -44,6 +46,7 @@ __all__ = [
     "measure_transfer",
     "dnl_inl",
     "stack_trees",
+    "takes_direct_path",
 ]
 
 
@@ -107,8 +110,13 @@ def make_reference_ladder(
     else:
         caps = jnp.ones((n,))
     csum = jnp.concatenate([jnp.zeros((1,)), jnp.cumsum(caps)])
-    m = np.round(np.arange(cfg.n_codes + 1) * n / cfg.n_codes).astype(np.int32)
-    return cfg.vdd * csum[m] / csum[n]
+    if n % cfg.n_codes == 0:
+        # m = t * n / 2^bits: a strided slice, which lowers to no gather
+        tapped = csum[:: n // cfg.n_codes]
+    else:
+        m = np.round(np.arange(cfg.n_codes + 1) * n / cfg.n_codes).astype(np.int32)
+        tapped = csum[m]
+    return cfg.vdd * tapped / csum[n]
 
 
 # ---------------------------------------------------------------------------
@@ -174,7 +182,8 @@ def _traverse(
     boundary_offset: Optional[jnp.ndarray] = None,
     seg: Optional[jnp.ndarray] = None,
 ):
-    """Walk an alphabetic search tree for every element of ``v`` in lockstep.
+    """Walk an alphabetic search tree for every element of ``v`` in lockstep,
+    comparison ``i`` adding its own draw of comparator noise (``sigma``).
 
     ``thr/left/right`` are flat ``(n,)`` tables, or ``(S, n)`` segmented tables
     indexed by ``seg`` (hybrid fine phase). ``boundary_offset`` shifts the
@@ -186,12 +195,7 @@ def _traverse(
 
     ref = jnp.zeros(v.shape, jnp.int32)
     ncmp = jnp.zeros(v.shape, jnp.int32)
-    if sigma > 0.0:
-        if key is None:
-            raise ValueError("comparator noise requires a PRNG key")
-        noise = sigma * jax.random.normal(key, (max_depth,) + v.shape)
-    else:
-        noise = jnp.zeros((max_depth,) + v.shape)
+    noise = sigma * jax.random.normal(key, (max_depth,) + v.shape)
 
     segmented = thr.ndim == 2
 
@@ -219,8 +223,68 @@ def _traverse(
 
 
 # ---------------------------------------------------------------------------
+# Direct path (noiseless comparators)
+# ---------------------------------------------------------------------------
+
+
+def takes_direct_path(cfg: ADCConfig) -> bool:
+    """Whether :func:`convert` resolves ``cfg``'s conversions by counting
+    the ladder boundaries each voltage reaches instead of walking the search
+    tree: every mode but ``ideal`` with noiseless comparators.
+
+    Noiseless, every comparison is ``v >= ladder[t]`` against a strictly
+    increasing ladder, and every search tree is alphabetic, so the leaf any
+    walk reaches is the number of inner boundaries ``v`` reaches, and the
+    comparisons it took are that leaf's depth.
+    """
+    return cfg.mode != "ideal" and not cfg.comparator_sigma > 0.0
+
+
+def _leaf_depths(depth: np.ndarray, fired: jnp.ndarray) -> jnp.ndarray:
+    """``depth[code]`` per element, with no gather. ``fired[t-1]`` is
+    ``v >= ladder[t]``; the boundaries a voltage reaches are a prefix, so
+    ``depth[code] = depth[0] + sum_t fired[t-1] * (depth[t] - depth[t-1])``."""
+    depth = np.asarray(depth, np.int32)
+    if (depth == depth[0]).all():
+        return jnp.full(fired.shape[1:], depth[0], jnp.int32)
+    step = np.diff(depth).reshape((-1,) + (1,) * (fired.ndim - 1))
+    return depth[0] + jnp.sum(jnp.where(fired, step, 0), axis=0, dtype=jnp.int32)
+
+
+def _convert_direct(v, cfg: ADCConfig, ladder, tree, fine_trees) -> ADCResult:
+    n = cfg.n_codes
+    fired = v[None] >= ladder[1:n].reshape((n - 1,) + (1,) * v.ndim)
+    codes = jnp.sum(fired, axis=0, dtype=jnp.int32)
+    if cfg.mode == "flash":
+        cmp = jnp.full(v.shape, n - 1, jnp.int32)
+        return ADCResult(codes, cmp, jnp.ones(v.shape, jnp.int32))
+    if cfg.mode in ("sar", "sar_asym"):
+        ncmp = _leaf_depths(_sar_tree(cfg, tree).depth, fired)
+        return ADCResult(codes, ncmp, ncmp)
+    # hybrid: every flash comparator fires, then the segment's SAR search
+    n_seg = 1 << cfg.flash_bits
+    depth = np.concatenate([t.depth for t in _fine_trees(cfg, fine_trees)])
+    fine_cmp = _leaf_depths(depth, fired)
+    return ADCResult(codes, (n_seg - 1) + fine_cmp, 1 + fine_cmp)
+
+
+# ---------------------------------------------------------------------------
 # Conversion front-ends
 # ---------------------------------------------------------------------------
+
+
+def _sar_tree(cfg: ADCConfig, tree: Optional[st.TreeTables]) -> st.TreeTables:
+    return tree if tree is not None else st.symmetric_tree(cfg.bits)
+
+
+def _fine_trees(cfg: ADCConfig, fine_trees) -> list[st.TreeTables]:
+    """The hybrid fine phase's per-segment trees (symmetric by default)."""
+    n_seg = 1 << cfg.flash_bits
+    if fine_trees is None:
+        return [st.symmetric_tree(cfg.bits - cfg.flash_bits)] * n_seg
+    if len(fine_trees) != n_seg:
+        raise ValueError(f"need {n_seg} fine trees, got {len(fine_trees)}")
+    return list(fine_trees)
 
 
 def convert(
@@ -236,7 +300,8 @@ def convert(
     ``tree`` supplies the asymmetric search tree for ``sar_asym``;
     ``fine_trees`` optionally supplies 2^flash_bits per-segment asymmetric
     trees for the hybrid fine phase. ``ladder`` overrides reference
-    generation (e.g. to reuse one mismatch draw across conversions).
+    generation (e.g. to reuse one mismatch draw across conversions); the
+    ladder must strictly increase, as every generated one does.
     """
     v = jnp.asarray(v)
     mismatch_key = cmp_key = None
@@ -250,16 +315,14 @@ def convert(
         z = jnp.zeros(v.shape, jnp.int32)
         return ADCResult(codes, z, z)
 
+    if takes_direct_path(cfg):
+        return _convert_direct(v, cfg, ladder, tree, fine_trees)
+    if cmp_key is None:
+        raise ValueError("comparator noise requires a PRNG key")
+
     if cfg.mode == "flash":
         n = cfg.n_codes
-        if cfg.comparator_sigma > 0.0:
-            if cmp_key is None:
-                raise ValueError("comparator noise requires a PRNG key")
-            noise = cfg.comparator_sigma * jax.random.normal(
-                cmp_key, (n - 1,) + v.shape
-            )
-        else:
-            noise = jnp.zeros((n - 1,) + v.shape)
+        noise = cfg.comparator_sigma * jax.random.normal(cmp_key, (n - 1,) + v.shape)
         thrs = ladder[1:n]  # boundaries 1..n-1
         fired = (v[None] + noise) >= thrs.reshape((n - 1,) + (1,) * v.ndim)
         codes = fired.sum(axis=0).astype(jnp.int32)
@@ -268,9 +331,7 @@ def convert(
         return ADCResult(codes, cmp, cyc)
 
     if cfg.mode in ("sar", "sar_asym"):
-        if cfg.mode == "sar" or tree is None:
-            tree = tree or st.symmetric_tree(cfg.bits)
-        thr, left, right, max_depth = _tree_to_jnp(tree)
+        thr, left, right, max_depth = _tree_to_jnp(_sar_tree(cfg, tree))
         codes, ncmp = _traverse(
             v, ladder, thr, left, right, max_depth, cfg.comparator_sigma, cmp_key
         )
@@ -281,23 +342,14 @@ def convert(
     n_seg = 1 << f
     seg_size = 1 << (cfg.bits - f)
     coarse_boundaries = np.arange(1, n_seg) * seg_size  # ladder indices
-    k1 = k2 = None
-    if cmp_key is not None:
-        k1, k2 = jax.random.split(cmp_key)
-    if cfg.comparator_sigma > 0.0:
-        noise = cfg.comparator_sigma * jax.random.normal(
-            k1, (n_seg - 1,) + v.shape
-        )
-    else:
-        noise = jnp.zeros((n_seg - 1,) + v.shape)
+    k1, k2 = jax.random.split(cmp_key)
+    noise = cfg.comparator_sigma * jax.random.normal(k1, (n_seg - 1,) + v.shape)
     cthr = ladder[jnp.asarray(coarse_boundaries)]
     fired = (v[None] + noise) >= cthr.reshape((n_seg - 1,) + (1,) * v.ndim)
     seg = fired.sum(axis=0).astype(jnp.int32)
 
     if fine_trees is not None:
-        if len(fine_trees) != n_seg:
-            raise ValueError(f"need {n_seg} fine trees, got {len(fine_trees)}")
-        thr, left, right, max_depth = stack_trees(fine_trees)
+        thr, left, right, max_depth = stack_trees(_fine_trees(cfg, fine_trees))
     else:
         t = st.symmetric_tree(cfg.bits - f)
         thr, left, right, max_depth = _tree_to_jnp(t)

@@ -49,6 +49,7 @@ from repro.fabric.mapper import model_forward_chain
 from repro.fabric.shard import (
     ShardedPlacement,
     _chip_noise_key,
+    _record_conversions,
     execute_sharded_matmul,
     shard_model,
 )
@@ -95,14 +96,12 @@ def _record_request(component: str, program, m: int, fused: bool) -> None:
             len(program.placements),
             help="Mapped matmuls executed.",
         )
-        obs_metrics.inc(
-            "fabric_conversions_total",
+        _record_conversions(
+            cim,
             sum(
                 cim.a_bits * cim.w_bits * m * math.ceil(sp.k / rows) * sp.n
                 for sp in program.placements
             ),
-            help="Analytic ADC conversions per executed matmul "
-            "(planes x rows x k-tiles x columns).",
         )
         obs_metrics.inc(
             "fabric_link_bits_total",

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.core.adc import takes_direct_path
 from repro.core.cim_linear import (
     CimStats,
     CiMConfig,
@@ -241,6 +242,25 @@ def shard_model(
         offset = (offset + sp.chip.n_weight_tiles) % chip_mesh.fabric.n_compute_arrays
         out.append(sp)
     return out
+
+
+def _record_conversions(cim: CiMConfig, conversions: int) -> None:
+    """Count ``conversions`` analytic ADC conversions, and those among them
+    whose converter takes ``core.adc``'s direct path (bit-plane mode with a
+    noiseless ADC): direct / total is the path's share."""
+    obs_metrics.inc(
+        "fabric_conversions_total",
+        conversions,
+        help="Analytic ADC conversions per executed matmul "
+        "(planes x rows x k-tiles x columns).",
+    )
+    direct = cim.mode == "bitplane" and takes_direct_path(cim.adc_config())
+    obs_metrics.inc(
+        "fabric_direct_conversions_total",
+        conversions if direct else 0,
+        help="ADC conversions resolved by the direct boundary count "
+        "(noiseless comparators), not the search-tree walk.",
+    )
 
 
 def _chip_noise_key(key: Optional[jax.Array], chip_index):
@@ -482,11 +502,8 @@ def execute_sharded_matmul(
         # as the unsharded op, and the link bits are the placement's
         # (C-1) * M * N * psum_bits reduce-scatter traffic
         obs_metrics.inc("fabric_matmuls_total", help="Mapped matmuls executed.")
-        obs_metrics.inc(
-            "fabric_conversions_total",
-            cim.a_bits * cim.w_bits * xm.shape[0] * math.ceil(k / fabric.rows) * n,
-            help="Analytic ADC conversions per executed matmul "
-            "(planes x rows x k-tiles x columns).",
+        _record_conversions(
+            cim, cim.a_bits * cim.w_bits * xm.shape[0] * math.ceil(k / fabric.rows) * n
         )
         obs_metrics.inc(
             "fabric_link_bits_total",

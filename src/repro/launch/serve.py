@@ -196,6 +196,7 @@ def serve_batch(
             fused = obs_metrics.get_value("fabric_requests_total", path="fused")
             fell = obs_metrics.get_value("fabric_requests_total", path="fallback")
             conv = obs_metrics.get_value("fabric_conversions_total")
+            direct = obs_metrics.get_value("fabric_direct_conversions_total")
             bits = obs_metrics.get_value("fabric_link_bits_total")
             modeled = obs_metrics.get_value("fabric_modeled_link_seconds")
             measured = obs_metrics.get_value("fabric_measured_collective_seconds")
@@ -203,14 +204,15 @@ def serve_batch(
             obs_trace.event(
                 "serve.request_summary", batch=batch_id, rows=b, total_tokens=total,
                 fused_requests=fused, fallback_requests=fell,
-                conversions=conv, link_bits=bits,
+                conversions=conv, direct_conversions=direct, link_bits=bits,
                 modeled_link_s=modeled, measured_collective_s=measured,
                 link_clock_calibration=calib,
             )
             print(
                 f"[serve] obs batch {b}x{total} tok on {fab['n_chips']} chip(s) "
                 f"[{fab['exec_backend']}]: fused {fused:.0f} / fallback "
-                f"{fell:.0f} requests; {conv:.3g} conversions, "
+                f"{fell:.0f} requests; {conv:.3g} conversions "
+                f"({direct:.3g} direct), "
                 f"{bits:.3g} link bits; link modeled {modeled:.3g} s vs "
                 f"measured {measured:.3g} s "
                 f"(link_clock_calibration {calib:.3g}); est. "

@@ -1,5 +1,7 @@
 """Memory-immersed ADC: mode equivalence, staircase, DNL/INL (paper Fig. 6)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -121,3 +123,165 @@ def test_invalid_configs_raise():
         adc.ADCConfig(mode="nope")
     with pytest.raises(ValueError):
         adc.ADCConfig(mode="hybrid", flash_bits=5, bits=5)
+
+
+# ---------------------------------------------------------------------------
+# noiseless direct path vs a plain walk of the tree tables
+# ---------------------------------------------------------------------------
+
+
+def _numpy_walk(v, ladder, tree, noise=None, offset=0):
+    """Walk ``tree``'s flat tables for every element of ``v``, one level at a
+    time: comparison ``i`` is ``v + noise[i] >= ladder[threshold[node] +
+    offset]``. Returns (codes, comparisons)."""
+    v = np.asarray(v, np.float32)
+    ladder = np.asarray(ladder, np.float32)
+    ref = np.zeros(v.shape, np.int64)
+    ncmp = np.zeros(v.shape, np.int64)
+    for i in range(int(tree.depth.max())):
+        inner = ref >= 0
+        node = np.maximum(ref, 0)
+        vi = v if noise is None else (v + noise[i]).astype(np.float32)
+        right = vi >= ladder[tree.threshold[node] + offset]
+        nxt = np.where(right, tree.right[node], tree.left[node])
+        ref = np.where(inner, nxt, ref)
+        ncmp += inner
+    return -ref - 1, ncmp
+
+
+def _numpy_convert(v, ladder, cfg, tree=None, fine_trees=None, noise=(None, None)):
+    """(codes, comparisons, cycles) of a plain conversion: flash counts every
+    boundary in one cycle; SAR walks its tree; hybrid counts the coarse
+    boundaries ``ladder[s * seg_size]`` (under ``noise[0]``), then walks that
+    segment's tree over its own boundaries (under ``noise[1]``)."""
+    v = np.asarray(v, np.float32)
+    ladder = np.asarray(ladder, np.float32)
+    if cfg.mode == "flash":
+        n = cfg.n_codes
+        codes = (v[None] >= ladder[1:n].reshape((n - 1,) + (1,) * v.ndim)).sum(0)
+        return codes, np.full(v.shape, n - 1), np.ones(v.shape)
+    if cfg.mode in ("sar", "sar_asym"):
+        codes, ncmp = _numpy_walk(v, ladder, tree or st.symmetric_tree(cfg.bits), noise[1])
+        return codes, ncmp, ncmp
+    n_seg = 1 << cfg.flash_bits
+    seg_size = cfg.n_codes // n_seg
+    trees = fine_trees or [st.symmetric_tree(cfg.bits - cfg.flash_bits)] * n_seg
+    seg = np.zeros(v.shape, np.int64)
+    for s in range(1, n_seg):
+        vs = v if noise[0] is None else (v + noise[0][s - 1]).astype(np.float32)
+        seg += vs >= ladder[s * seg_size]
+    codes = np.zeros(v.shape, np.int64)
+    ncmp = np.zeros(v.shape, np.int64)
+    for s, t in enumerate(trees):
+        m = seg == s
+        fine_noise = None if noise[1] is None else noise[1][:, m]
+        c, k = _numpy_walk(v[m], ladder, t, fine_noise, offset=s * seg_size)
+        codes[m] = s * seg_size + c
+        ncmp[m] = k
+    return codes, (n_seg - 1) + ncmp, 1 + ncmp
+
+
+def _tree(mode, bits):
+    if mode == "sar_asym":
+        return st.optimal_tree(analytic_code_pmf(16, bits))
+    return st.symmetric_tree(bits)
+
+
+def _segment_trees(bits, flash_bits):
+    """Per-segment optimal trees for the hybrid fine phase."""
+    pmf = analytic_code_pmf(16, bits)
+    size = 1 << (bits - flash_bits)
+    segs = [pmf[s * size : (s + 1) * size] for s in range(1 << flash_bits)]
+    return [st.optimal_tree(p / max(p.sum(), 1e-12)) for p in segs]
+
+
+def _check_noiseless(cfg, mismatch, seed, **trees):
+    """Noiseless ``convert`` gives the plain conversion's codes, comparisons
+    and cycles exactly: at every discrete MAV level of a 16-row array (with
+    the half-LSB bias), on every ladder boundary, below 0 and above vdd."""
+    if mismatch:
+        cfg = dataclasses.replace(cfg, ref_mismatch_sigma=0.05)
+    key = jax.random.PRNGKey(seed) if mismatch else None
+    ladder = adc.make_reference_ladder(
+        cfg, None if key is None else jax.random.split(key)[0]
+    )
+    v = np.concatenate([
+        np.arange(17) / 16 + 0.5 / cfg.n_codes,
+        np.asarray(ladder),
+        [-0.25, -1e-6, cfg.vdd, cfg.vdd + cfg.lsb, 1.5 * cfg.vdd],
+    ]).astype(np.float32)
+    res = adc.convert(jnp.asarray(v), cfg, key=key, **trees)
+    codes, ncmp, cycles = _numpy_convert(v, ladder, cfg, **trees)
+    assert res.codes.dtype == res.comparisons.dtype == res.cycles.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(res.codes), codes)
+    np.testing.assert_array_equal(np.asarray(res.comparisons), ncmp)
+    np.testing.assert_array_equal(np.asarray(res.cycles), cycles)
+
+
+@pytest.mark.parametrize("mismatch", [False, True])
+@pytest.mark.parametrize("bits", [4, 5, 6])
+@pytest.mark.parametrize("mode", ["sar", "sar_asym", "flash"])
+def test_noiseless_convert_matches_tree_walk(mode, bits, mismatch):
+    cfg = adc.ADCConfig(bits=bits, mode=mode, n_ref_columns=max(32, 1 << bits))
+    trees = {"tree": _tree(mode, bits)} if mode != "flash" else {}
+    _check_noiseless(cfg, mismatch, 11 + bits, **trees)
+
+
+@pytest.mark.parametrize("mismatch", [False, True])
+@pytest.mark.parametrize("fine", [False, True])
+@pytest.mark.parametrize("flash_bits", [1, 2, 3])
+@pytest.mark.parametrize("bits", [4, 5, 6])
+def test_noiseless_hybrid_matches_tree_walk(bits, flash_bits, fine, mismatch):
+    """The hybrid converter, with symmetric or per-segment optimal fine trees."""
+    cfg = adc.ADCConfig(
+        bits=bits, mode="hybrid", flash_bits=flash_bits,
+        n_ref_columns=max(32, 1 << bits),
+    )
+    trees = {"fine_trees": _segment_trees(bits, flash_bits)} if fine else {}
+    _check_noiseless(cfg, mismatch, 7 * bits + flash_bits, **trees)
+
+
+@pytest.mark.parametrize("mode", ["sar", "sar_asym", "hybrid"])
+def test_noiseless_convert_lowers_without_gather_or_while(mode):
+    """At the fabric's tile shape (a_bits, w_bits, M, K/16, 32 columns) the
+    noiseless conversion is compares and sums; with comparator noise the
+    walk stays (segmented per flash segment for hybrid), and its codes are
+    the plain walk's under the same draws."""
+    cfg = adc.ADCConfig(bits=5, mode=mode)
+    if mode == "hybrid":
+        trees = {"fine_trees": _segment_trees(5, cfg.flash_bits)}
+        depth = max(t.max_depth for t in trees["fine_trees"])
+    else:
+        trees = {"tree": _tree(mode, 5)}
+        depth = trees["tree"].max_depth
+    shape = (4, 4, 4, 36, 32)
+    rng = np.random.default_rng(0)
+    v = (rng.binomial(16, 0.25, shape) / 16 + 0.5 / 32).astype(np.float32)
+
+    hlo = jax.jit(lambda x: adc.convert(x, cfg, **trees)).lower(v).as_text()
+    assert "gather" not in hlo and "while" not in hlo
+
+    noisy = dataclasses.replace(cfg, comparator_sigma=0.02)
+    key = jax.random.PRNGKey(5)
+    fn = jax.jit(lambda x: adc.convert(x, noisy, key=key, **trees))
+    assert "while" in fn.lower(v).as_text()
+    res = fn(v)
+    cmp_key = jax.random.split(key)[1]
+    if mode == "hybrid":
+        k1, k2 = jax.random.split(cmp_key)
+        n_seg = 1 << cfg.flash_bits
+        noise = (
+            np.asarray(noisy.comparator_sigma * jax.random.normal(k1, (n_seg - 1,) + shape)),
+            np.asarray(noisy.comparator_sigma * jax.random.normal(k2, (depth,) + shape)),
+        )
+    else:
+        noise = (None, np.asarray(
+            noisy.comparator_sigma * jax.random.normal(cmp_key, (depth,) + shape)
+        ))
+    codes, ncmp, cycles = _numpy_convert(
+        v, adc.make_reference_ladder(noisy), noisy, noise=noise, **trees
+    )
+    np.testing.assert_array_equal(np.asarray(res.codes), codes)
+    np.testing.assert_array_equal(np.asarray(res.comparisons), ncmp)
+    np.testing.assert_array_equal(np.asarray(res.cycles), cycles)
+    assert (codes != np.asarray(adc.convert(v, cfg, **trees).codes)).any()

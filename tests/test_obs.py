@@ -4,6 +4,7 @@ reason taxonomy, and — the load-bearing part — the neutrality guarantees:
 fused collective censuses and bit-exact outputs must be identical with
 observability on or off. ``tests/conftest.py`` forces 8 host devices."""
 
+import dataclasses
 import json
 import time
 
@@ -384,6 +385,32 @@ def test_obs_does_not_change_fused_graph_logits_1x1_noisy():
     assert names == ["fabric.graph.prepare", "fabric.graph.forward"]
 
 
+@pytest.mark.parametrize("sigma", [0.0, 0.05])
+def test_direct_conversions_counted_on_the_fused_graph(sigma):
+    """A noiseless fused call resolves every conversion on the ADC's direct
+    path; a noisy one walks the search tree for all of them."""
+    from repro.configs.base import ModelConfig
+    from repro.models.transformer import init_transformer
+
+    cfg = ModelConfig(
+        name="obs-direct", family="dense", n_layers=1, d_model=64,
+        vocab=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+        pad_vocab_multiple=16, param_dtype="float32",
+        compute_dtype="float32",
+    )
+    cim = dataclasses.replace(NOISY, comparator_sigma=sigma)
+    prog = compile_graph_forward(cfg, ChipMeshConfig(fabric=FB), cim, tokens=8)
+    ws = transformer_graph_weights(init_transformer(jax.random.PRNGKey(0), cfg), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 4, cfg.d_model))
+    with obs.collecting():
+        prog(x, ws, key=jax.random.PRNGKey(7))
+        assert obs.get_value("fabric_requests_total", path="fused") == 1.0
+        total = obs.get_value("fabric_conversions_total")
+        direct = obs.get_value("fabric_direct_conversions_total")
+    assert total > 0
+    assert direct == (total if sigma == 0.0 else 0.0)
+
+
 # ---------------------------------------------------------------------------
 # calibration constant + serve summary line
 # ---------------------------------------------------------------------------
@@ -432,6 +459,7 @@ def test_serve_obs_summary_line(capsys):
             if l.startswith("[serve] obs")]
     assert len(line) == 1
     assert "fused" in line[0] and "link_clock_calibration" in line[0]
+    assert "direct" in line[0]
     assert reg.counter("serve_requests_total").value() == 2.0
     assert reg.histogram("serve_prefill_seconds").count() == 1
     assert reg.counter("fabric_ema_bits_total").value() > 0
